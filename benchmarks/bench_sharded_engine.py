@@ -1,14 +1,16 @@
 """Sharded-engine benchmark: the ``("cloud", "client")`` mesh engine vs
 the single-device ``lax.scan`` engine, plus the 1-device parity config.
 
-Two phases, each in its own subprocess (the device count is process
-global):
+Two phases, both in this process over the devices it sees (a chip
+belongs to one process, so no phase runs in a child). On the CPU the
+caller fakes host devices through ``XLA_FLAGS`` (README, "Multi-device
+simulation"):
 
-* ``parity``  — 1 forced host device: the sharded engine on a 1×1 mesh
+* ``parity``  — the sharded engine on a 1×1 mesh (the first device)
   against the scan engine on the small test config; reports the max
   reputation/accuracy deviation and the byte/cost-equality booleans
   (the acceptance contract, measured — not just asserted in tests).
-* ``fleet``   — 8 forced host devices: N=1024 clients / 4 clouds at
+* ``fleet``   — every visible device: N=1024 clients / 4 clouds at
   FULL participation ((8, 8, 3) inputs, d≈54k), the sharded engine's
   sweet spot — masked all-client training is exactly the round's work.
   Reports steady-state rounds/sec for both engines, the speedup, a
@@ -27,20 +29,10 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import re
-import subprocess
-import sys
 import time
 from pathlib import Path
-from typing import Tuple
 
 import numpy as np
-
-_MARKER = "BENCH_PHASE_JSON:"
-_REPO_ROOT = Path(__file__).resolve().parents[1]
-
-FLEET_N_DEVICES = 8
 
 
 def _fleet_config():
@@ -107,7 +99,7 @@ def _concurrency_probe() -> float:
 
 
 # ---------------------------------------------------------------------------
-# phases (each runs in a subprocess with its own forced device count)
+# phases
 
 def phase_parity(rounds: int = 3) -> dict:
     from repro.federated import (make_data, run_simulation,
@@ -217,33 +209,13 @@ def phase_fleet(rounds: int = 6) -> dict:
 # ---------------------------------------------------------------------------
 # orchestration
 
-def _spawn(phase: str, rounds: int, n_devices: int) -> dict:
-    env = dict(os.environ)
-    flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                   env.get("XLA_FLAGS", ""))
-    env["XLA_FLAGS"] = (f"{flags} --xla_force_host_platform_device_count="
-                        f"{n_devices}").strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_sharded_engine",
-         "--phase", phase, "--rounds", str(rounds)],
-        env=env, cwd=_REPO_ROOT, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(f"phase {phase!r} failed:\n{proc.stdout}\n"
-                           f"{proc.stderr}")
-    for line in reversed(proc.stdout.splitlines()):
-        if line.startswith(_MARKER):
-            return json.loads(line[len(_MARKER):])
-    raise RuntimeError(f"phase {phase!r} emitted no result marker:\n"
-                       f"{proc.stdout}\n{proc.stderr}")
-
-
 def run(rounds: int = 6,
         out_path: str = "BENCH_sharded_engine.json") -> dict:
     from benchmarks.common import emit
     from repro.telemetry.provenance import stamp
 
-    parity = _spawn("parity", max(3, rounds // 2), 1)
-    fleet = _spawn("fleet", rounds, FLEET_N_DEVICES)
+    parity = phase_parity(max(3, rounds // 2))
+    fleet = phase_fleet(rounds)
 
     result = {**fleet, "parity_1dev": parity, "provenance": stamp()}
     emit("sharded_engine/scan",
@@ -262,16 +234,10 @@ def run(rounds: int = 6,
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--phase", choices=["parity", "fleet"], default=None)
     ap.add_argument("--rounds", type=int, default=6)
     args = ap.parse_args()
-    if args.phase is None:
-        print("name,us_per_call,derived")
-        print(json.dumps(run(rounds=args.rounds), indent=2))
-        return
-    fn = phase_parity if args.phase == "parity" else phase_fleet
-    out = fn(rounds=args.rounds)
-    print(_MARKER + json.dumps(out))
+    print("name,us_per_call,derived")
+    print(json.dumps(run(rounds=args.rounds), indent=2))
 
 
 if __name__ == "__main__":

@@ -1,6 +1,8 @@
 """Benchmark entrypoint: one module per paper table/figure.
-Prints ``name,us_per_call,derived`` CSV. Rounds are reduced by default
-(CPU container); raise --rounds for the full-fidelity sweep."""
+Prints ``name,us_per_call,derived`` CSV. Rounds are reduced by default;
+raise --rounds for the full-fidelity sweep. Every selected benchmark runs
+in this one process (a chip belongs to one process), over the devices it
+sees, with the persistent compile cache placed by ``repro.launch.cache``."""
 from __future__ import annotations
 
 import argparse
@@ -17,6 +19,9 @@ def main() -> None:
                          "fig8,kernels,round_engine,sharded_engine")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     t0 = time.time()

@@ -7,7 +7,8 @@ client → edge → global upload path, so cheap intra-cloud links can stay
 uncompressed while expensive cross-cloud egress compresses aggressively.
 
 Hot paths are fused Pallas kernels (repro.kernels.topk_mask / quantize,
-interpret=True on CPU); exact wire bytes feed repro.core.cost.CostModel.
+compiled on TPU, interpreted on CPU); exact wire bytes feed
+repro.core.cost.CostModel.
 """
 from repro.compress.base import (Codec, CompressedUpdate, ef_step,
                                  ef_step_masked, make_codec)

@@ -214,20 +214,6 @@ def static_from_shard(flcfg: FLConfig, topo: CloudTopology, method: str,
 
 
 # ---------------------------------------------------------------------------
-# shard_map across jax versions (same dispatch as repro.train.steps; the
-# sharded engine is fully manual over both axes, so the 0.4.x legacy
-# entry point with check_rep=False is numerically identical)
-
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-    return legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
-
-
-# ---------------------------------------------------------------------------
 # the compiled sharded engine
 
 @dataclass(frozen=True)
@@ -602,12 +588,13 @@ def compiled_sharded(shard_static: ShardStatic) -> CompiledShard:
     def _program_step(state, data, t):
         return round_step_local(state, data, t)
 
-    run_jit = jax.jit(_shard_map(
-        _program, mesh=mesh,
-        in_specs=(state_specs, data_specs, P()), out_specs=out_specs))
-    step_jit = jax.jit(_shard_map(
-        _program_step, mesh=mesh,
-        in_specs=(state_specs, data_specs, P()), out_specs=out_specs))
+    # manual over both mesh axes
+    run_jit = jax.jit(jax.shard_map(
+        _program, mesh=mesh, in_specs=(state_specs, data_specs, P()),
+        out_specs=out_specs, check_vma=False))
+    step_jit = jax.jit(jax.shard_map(
+        _program_step, mesh=mesh, in_specs=(state_specs, data_specs, P()),
+        out_specs=out_specs, check_vma=False))
 
     def _place(tree, specs):
         return jax.tree.map(

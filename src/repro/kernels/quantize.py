@@ -10,9 +10,10 @@ so that E_u[q * scale / L] = x — the unbiasedness the trust statistics
 rely on (they are computed on dequantized updates downstream).
 
 The randomness is an explicit input rather than ``pltpu.prng_random_bits``
-so the kernel is bit-reproducible under ``interpret=True`` on CPU (this
-container) and trivially checkable against ``ref.stochastic_quantize_ref``;
-on real TPU hardware the noise tile streams from HBM alongside X.
+so the kernel draws the same noise in interpret mode on the CPU and
+compiled on the TPU, and is checkable against
+``ref.stochastic_quantize_ref``; on the chip the noise tile streams from
+HBM alongside X.
 
 TPU mapping: grid over N-blocks x D-blocks, all element-wise VPU work on
 (BN, BD) VMEM tiles; the (BN, 1) scale column rides along each row block.
@@ -39,7 +40,7 @@ def _kernel(x_blk, s_blk, u_blk, q_blk, *, levels: int, eps: float):
 
 def stochastic_quantize(x: Array, scale: Array, noise: Array, *,
                         levels: int, block_n: int = 8, block_d: int = 512,
-                        eps: float = 1e-12, interpret: bool = True) -> Array:
+                        eps: float = 1e-12, interpret: bool) -> Array:
     """Quantize (N, D) to int32 levels in [-levels, levels].
 
     ``scale``: (N,) per-row scales (max |x| for the QSGD linf variant).

@@ -32,7 +32,7 @@ def _kernel(g_blk, thr_blk, out_blk):
 
 
 def topk_mask(grads: Array, thr: Array, *, block_n: int = 8,
-              block_d: int = 512, interpret: bool = True) -> Array:
+              block_d: int = 512, interpret: bool) -> Array:
     """Zero every |G[i, d]| < thr[i]. See ref.topk_mask_ref."""
     n, d = grads.shape
     bn = min(block_n, n)

@@ -11,10 +11,13 @@ TPU mapping mirrors ``trust_score.py``: grid over N-blocks × D-blocks
 tile of the per-row reference matrix plus the broadcast (BD,) aggregate
 slice, accumulating per-row <g, ref>, ‖g‖², ‖ref‖² and the
 sign-agreement count in a (BN, 8) VMEM scratch. The final D-block folds
-in the (pre-reduced) median norm and delivery weights and writes the
-four feature vectors. Zero-padding of both axes is safe by
-construction: padded coordinates contribute 0 to every dot product and
-never count as sign agreement, and padded rows carry w = 0.
+in the (pre-reduced) median norm and the (BN, 1) delivery-weight column
+and writes the four features as the columns of one (BN, N_FEATURES)
+output block (per-row vectors ride in 2-D blocks, which Mosaic tiles for
+any BN that is a multiple of 8 or the whole padded M). Zero-padding of
+both axes is safe by construction: padded coordinates contribute 0 to
+every dot product and never count as sign agreement, and padded rows
+carry w = 0.
 """
 from __future__ import annotations
 
@@ -25,11 +28,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.features import N_FEATURES
+
 Array = jax.Array
 
 
-def _kernel(g_blk, ref_blk, gbar_blk, med_blk, w_blk,
-            f0_out, f1_out, f2_out, f3_out, acc,
+def _kernel(g_blk, ref_blk, gbar_blk, med_blk, w_blk, out_blk, acc,
             *, n_dblocks: int, d_true: int, eps: float):
     d_idx = pl.program_id(1)
 
@@ -55,7 +59,7 @@ def _kernel(g_blk, ref_blk, gbar_blk, med_blk, w_blk,
 
         med_raw = med_blk[0, 0]
         med = jnp.where(jnp.isnan(med_raw) | ~(med_raw > 0), 1.0, med_raw)
-        w = w_blk[...].astype(jnp.float32)
+        w = w_blk[:, 0]
 
         f0 = 1.0 / (1.0 + jnp.abs(jnp.log(jnp.maximum(norm_g, eps) / med)))
         f1 = jnp.maximum(dot_ref / jnp.maximum(norm_g * norm_r, eps), 0.0)
@@ -64,15 +68,15 @@ def _kernel(g_blk, ref_blk, gbar_blk, med_blk, w_blk,
         x = f1 * jnp.minimum(ratio, 1.0 / ratio)
         f3 = x / (1.0 + x)
 
-        f0_out[...] = f0 * w
-        f1_out[...] = f1 * w
-        f2_out[...] = f2 * w
-        f3_out[...] = f3 * w
+        out_blk[:, 0] = f0 * w
+        out_blk[:, 1] = f1 * w
+        out_blk[:, 2] = f2 * w
+        out_blk[:, 3] = f3 * w
 
 
 def trust_features(grads: Array, refs: Array, gbar: Array, med: Array,
                    w: Array, *, block_n: int = 8, block_d: int = 512,
-                   eps: float = 1e-12, interpret: bool = True) -> Array:
+                   eps: float = 1e-12, interpret: bool) -> Array:
     """Fused (M, N_FEATURES) feature pass over (M, D). Pads M and D to
     block multiples; ``med`` is the (possibly NaN) selected-median norm
     and is sanitized in-kernel exactly like the jnp oracle."""
@@ -84,12 +88,12 @@ def trust_features(grads: Array, refs: Array, gbar: Array, med: Array,
     g = jnp.pad(grads, ((0, pm), (0, pd)))
     r = jnp.pad(refs, ((0, pm), (0, pd)))
     gb = jnp.pad(gbar, (0, pd))[None, :]
-    wp = jnp.pad(w.astype(jnp.float32), (0, pm))
+    wp = jnp.pad(w.astype(jnp.float32), (0, pm))[:, None]
     med_arr = jnp.asarray(med, jnp.float32).reshape(1, 1)
     mm, dd = g.shape
     n_dblocks = dd // bd
 
-    f0, f1, f2, f3 = pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, n_dblocks=n_dblocks, d_true=d, eps=eps),
         grid=(mm // bn, n_dblocks),
         in_specs=[
@@ -97,16 +101,11 @@ def trust_features(grads: Array, refs: Array, gbar: Array, med: Array,
             pl.BlockSpec((bn, bd), lambda i, j: (i, j)),
             pl.BlockSpec((1, bd), lambda i, j: (0, j)),
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
+            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((mm,), jnp.float32)] * 4,
+        out_specs=pl.BlockSpec((bn, N_FEATURES), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((mm, N_FEATURES), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, 8), jnp.float32)],
         interpret=interpret,
     )(g, r, gb, med_arr, wp)
-    return jnp.stack([f0[:m], f1[:m], f2[:m], f3[:m]], axis=1)
+    return out[:m]

@@ -26,7 +26,7 @@ def _kernel(g_blk, w_blk, out_blk):
 
 def weighted_agg(grads: Array, ts: Array, norms: Array, ref_norm: Array,
                  *, block_d: int = 512, eps: float = 1e-12,
-                 interpret: bool = True) -> Array:
+                 interpret: bool) -> Array:
     """(N, D) x weights -> (D,) aggregate. See ref.weighted_agg_ref."""
     n, d = grads.shape
     bd = min(block_d, d)

@@ -1,6 +1,8 @@
 """Multi-pod dry-run: prove that every (architecture x input-shape x mesh)
 combination lowers AND compiles on the production mesh, and extract the
-roofline terms from the compiled artifact.
+roofline terms from the compiled artifact. The target is TPU v5e
+(``TARGET_KIND``): roofline terms are reckoned against its published
+peaks (``repro.roofline.analyze.PEAKS``).
 
 Usage:
   python -m repro.launch.dryrun --arch gemma2-2b --shape train_4k
@@ -35,6 +37,7 @@ from repro.serve.decode import make_prefill_step, make_serve_step
 from repro.train.steps import MeshTopology, make_fl_train_step
 
 PARAM_DTYPE = jnp.bfloat16
+TARGET_KIND = "TPU v5 lite"      # jax device_kind of a TPU v5e chip
 REF_BATCH_PER_CLOUD = 2
 
 
@@ -120,8 +123,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     try:
         mesh = make_production_mesh(multi_pod=multi_pod)
         _, compiled, mf = lower_pair(arch, shape_name, mesh, flcfg)
-        report = analyze(compiled, mesh, arch=arch, shape=shape_name,
-                         model_flops=mf)
+        report = analyze(compiled, mesh, kind=TARGET_KIND, arch=arch,
+                         shape=shape_name, model_flops=mf)
         rec = {"status": "ok", "compile_s": round(time.time() - t0, 1),
                **report.to_json()}
     except Exception as e:  # noqa: BLE001 — record failures, keep sweeping

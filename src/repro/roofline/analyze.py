@@ -1,9 +1,10 @@
 """Roofline analysis from compiled dry-run artifacts (no TPU required).
 
-Terms (per chip, seconds):
-  compute    = HLO_FLOPs_per_device / PEAK_FLOPS
-  memory     = HLO_bytes_per_device / HBM_BW
-  collective = Σ per-device collective payload x type-multiplier / ICI_BW
+Terms (per chip, seconds), against the published peaks of the chip
+kind the caller reckons for (``PEAKS``, keyed by ``Device.device_kind``):
+  compute    = HLO_FLOPs_per_device / flops
+  memory     = HLO_bytes_per_device / hbm_bw
+  collective = Σ per-device collective payload x type-multiplier / ici_bw
 
 Collective bytes are parsed from the partitioned HLO text (SPMD: shapes
 are per-device shards; every device executes each collective once).
@@ -22,11 +23,34 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12       # bf16
-HBM_BW = 819e9            # bytes/s
-ICI_BW = 50e9             # bytes/s per link
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+    flops: float          # bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    ici_bw: float         # chip-to-chip bytes/s per link
+
+
+# Keyed by ``jax.Device.device_kind``. TPU v5e ("TPU v5 lite"): Google
+# Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of chip-to-chip interconnect over 4 links (50 GB/s each).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
 EGRESS_PER_GB = 0.09      # $ (AWS egress, paper §I)
+
+
+def peaks_for(kind: str) -> ChipPeaks:
+    """The peaks of chip ``kind``; a kind with no published entry is an
+    error, never a default."""
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind {kind!r}; "
+                         f"known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
@@ -73,6 +97,7 @@ class RooflineReport:
     arch: str = ""
     shape: str = ""
     mesh: str = ""
+    kind: str = ""
     chips: int = 0
     flops_per_device: float = 0.0
     bytes_per_device: float = 0.0
@@ -166,8 +191,11 @@ def pod_map(mesh) -> Optional[np.ndarray]:
     return pod_of
 
 
-def analyze(compiled, mesh, *, arch: str = "", shape: str = "",
+def analyze(compiled, mesh, *, kind: str, arch: str = "", shape: str = "",
             model_flops: float = 0.0) -> RooflineReport:
+    """Roofline terms of ``compiled`` on ``mesh``, reckoned for chips of
+    ``kind`` (a ``PEAKS`` key, e.g. ``"TPU v5 lite"``)."""
+    peaks = peaks_for(kind)
     chips = int(np.prod(list(mesh.shape.values())))
     cost = compiled.cost_analysis()
     if isinstance(cost, list):
@@ -183,9 +211,9 @@ def analyze(compiled, mesh, *, arch: str = "", shape: str = "",
     for op in ops:
         by_kind[op.kind] = by_kind.get(op.kind, 0) + 1
 
-    compute_s = flops / PEAK_FLOPS
-    memory_s = byts / HBM_BW
-    collective_s = coll / ICI_BW
+    compute_s = flops / peaks.flops
+    memory_s = byts / peaks.hbm_bw
+    collective_s = coll / peaks.ici_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)
@@ -207,7 +235,7 @@ def analyze(compiled, mesh, *, arch: str = "", shape: str = "",
 
     useful = model_flops / (flops * chips) if flops else 0.0
     return RooflineReport(
-        arch=arch, shape=shape,
+        arch=arch, shape=shape, kind=kind,
         mesh="x".join(f"{k}{v}" for k, v in mesh.shape.items()),
         chips=chips, flops_per_device=flops, bytes_per_device=byts,
         collective_bytes_per_device=coll, cross_pod_bytes_per_device=cross,

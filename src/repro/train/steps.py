@@ -111,24 +111,6 @@ def _phi(ll: Any, ll_bar: Any, eps: float = 1e-12) -> Array:
     return jax.nn.relu(cos) * n_i
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names):
-    """jax.shard_map across jax versions. >=0.6 exposes it at top level and
-    keeps the model axis auto (GSPMD) via ``axis_names``. On 0.4.x the
-    equivalent would be ``jax.experimental.shard_map(..., auto=<complement
-    of axis_names>)``, but partial-auto shard_map CHECK-crashes the XLA CPU
-    SPMD partitioner of jaxlib 0.4.36 ("IsManualSubgroup" check), so we run
-    fully manual there instead: numerics are identical, the model axis just
-    computes replicated work (acceptable for the CPU smoke/dry-run scale
-    this fallback serves)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-    return legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # two_phase strategy (paper-faithful, shard_map)
 
@@ -260,11 +242,12 @@ def make_two_phase_step(model: Model, mesh: Mesh, flcfg: FLConfig,
     dax = topo.daxes if len(topo.daxes) > 1 else topo.daxes[0]
 
     def step(params, opt_state, rep, batch, ref_batch):
-        mapped = _shard_map(
+        # manual over the data axes; the model axis stays auto (GSPMD)
+        mapped = jax.shard_map(
             per_group, mesh=mesh,
             in_specs=(P(), P(), P(dax), P()),
             out_specs=(P(), P(), P()),
-            axis_names=set(topo.daxes),
+            axis_names=set(topo.daxes), check_vma=False,
         )
         g_global, new_rep, metrics = mapped(params, rep, batch, ref_batch)
         # optimizer update at GSPMD level: ZeRO-1 — moments are sharded

@@ -1,6 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (hypothesis) +
-directed cases. Kernels run in interpret mode (CPU container; TPU is the
-compile target)."""
+directed cases. On the CPU the ops wrappers run the kernels in interpret
+mode; tests/test_tpu_compile.py compiles them for the chip."""
 import jax
 import jax.numpy as jnp
 import numpy as np

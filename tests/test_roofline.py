@@ -3,8 +3,8 @@ term arithmetic on synthetic HLO text."""
 import numpy as np
 import pytest
 
-from repro.roofline.analyze import (CollectiveOp, _shape_bytes,
-                                    parse_collectives)
+from repro.roofline.analyze import (CollectiveOp, _shape_bytes, analyze,
+                                    parse_collectives, peaks_for)
 
 HLO = """
 HloModule test
@@ -55,3 +55,39 @@ def test_iota_replica_groups():
     assert len(ops) == 1 and not ops[0].cross_pod     # groups {0,1},{2,3}
     pod_of2 = np.array([0, 1, 0, 1])
     assert parse_collectives(hlo, pod_of2)[0].cross_pod
+
+
+def test_peaks_keyed_by_device_kind():
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for("cpu")
+
+
+class _Compiled:
+    """Stands in for a compiled program: one second of v5e compute and
+    one of HBM traffic, plus the collectives of ``HLO``."""
+
+    def cost_analysis(self):
+        return {"flops": 197e12, "bytes accessed": 819e9}
+
+    def as_text(self):
+        return HLO
+
+    def memory_analysis(self):
+        raise NotImplementedError
+
+
+class _Mesh:
+    shape = {"cloud": 2, "client": 2}
+    axis_names = ("cloud", "client")
+
+
+def test_analyze_reckons_for_the_given_kind():
+    rep = analyze(_Compiled(), _Mesh(), kind="TPU v5 lite")
+    assert rep.kind == "TPU v5 lite" and rep.chips == 4
+    assert rep.compute_s == pytest.approx(1.0)
+    assert rep.memory_s == pytest.approx(1.0)
+    assert rep.n_collectives == 5
+    with pytest.raises(ValueError, match="no published peaks"):
+        analyze(_Compiled(), _Mesh(), kind="TPU v4")
