@@ -62,6 +62,13 @@ from repro.telemetry.taps import TapSpec
 
 Array = jax.Array
 
+# Profiles name the round's device ops by the jax.named_scope phases
+# below, which live only in HLO metadata. JAX's persistent compile cache
+# leaves metadata out of its key by default, so an executable compiled
+# from another build of this program would be served with that build's
+# scope names; keying on metadata keeps a profile's names the program's.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
 _GB = 1024.0 ** 3
 REF_BATCH = 32          # reference LocalTrain batch (client default)
 
@@ -705,9 +712,10 @@ def _compiled(static: EngineStatic,
                 # multi-seed batch stays cache-resident)
                 eps = 1e-12
                 f32 = flat_sel.dtype
-                ref_tree = train_ref(state.params, data.ref_x, data.ref_y,
-                                     key)
-                ref_flat = ravel_rows(ref_tree)
+                with jax.named_scope("ref_train"):
+                    ref_tree = train_ref(state.params, data.ref_x,
+                                         data.ref_y, key)
+                    ref_flat = ravel_rows(ref_tree)
                 ref_ll = ref_flat[:, ll_idx]
                 sel_cloud = cloud_of_j[sel_idx]                   # (m,)
                 onehot = jax.nn.one_hot(sel_cloud, k, dtype=f32)  # (m, K)
@@ -764,9 +772,10 @@ def _compiled(static: EngineStatic,
                               / jnp.maximum(ts_cloud, eps)[:, None])
                 if edge_wire_active:
                     active = (onehot.T @ w > 0)[:, None]
-                    cloud_aggs, res_edge = _edge_wire(
-                        cloud_aggs, res_edge, active,
-                        jax.random.fold_in(key, _FOLD_EDGE_WIRE))
+                    with jax.named_scope("edge_codec"):
+                        cloud_aggs, res_edge = _edge_wire(
+                            cloud_aggs, res_edge, active,
+                            jax.random.fold_in(key, _FOLD_EDGE_WIRE))
                 # empty/zero-trust clouds fall back to their reference
                 cloud_aggs = jnp.where((ts_cloud > eps)[:, None],
                                        cloud_aggs, ref_flat)
@@ -792,9 +801,10 @@ def _compiled(static: EngineStatic,
                     update = coordinate_median(u)
                 else:  # fltrust — zero (dropped) rows get ts=0, so it's
                        # already masked-delivery safe
-                    ref_tree = train_ref(state.params, data.ref_x,
-                                         data.ref_y, key)
-                    ref_flat = ravel_rows(ref_tree)
+                    with jax.named_scope("ref_train"):
+                        ref_tree = train_ref(state.params, data.ref_x,
+                                             data.ref_y, key)
+                        ref_flat = ravel_rows(ref_tree)
                     update = fltrust(u, jnp.mean(ref_flat, axis=0))
 
             # apply: w <- w - eta * g  (g is a model delta)

@@ -284,51 +284,64 @@ class FLServer:
 
     # -- one round --------------------------------------------------------------
     def run_round(self, t: int) -> RoundMetrics:
-        ctx = self._telemetry_ctx
-        if ctx is None:
-            if self._eng is not None:
-                return self._run_round_engine(t)
-            return self._run_round_host(t)
-        # span events separate compile (first round traces + compiles
-        # the step) from steady-state execute
+        # the ``round`` span is a profiler annotation on every call; with
+        # telemetry its span event separates compile (the first round
+        # traces + compiles the step) from steady-state execute
         phase = "execute" if self._stepped else "compile+execute"
-        with spans.span("round", ctx, phase=phase, t=t):
-            metrics = (self._run_round_engine(t) if self._eng is not None
+        with spans.span("round", self._telemetry_ctx, phase=phase, t=t):
+            metrics = (self._run_round_engine(t, phase)
+                       if self._eng is not None
                        else self._run_round_host(t))
         self._stepped = True
         return metrics
 
-    def _run_round_engine(self, t: int) -> RoundMetrics:
+    def _run_round_engine(self, t: int, phase: str) -> RoundMetrics:
         """Engine driver: one jitted device call, then host-side float64
         accounting from the delivered mask (byte-exact at any scale and
         bit-identical to the lax.scan driver, which reduces the same
-        per-round masks)."""
-        state, out = self._eng.step(self._eng_state, self._eng_data, t)
+        per-round masks). Its host time falls into profiler spans:
+        ``host.dispatch`` (the step call), one ``host.fetch`` per
+        device→host read, and ``host.account``."""
+        ctx = self._telemetry_ctx
+
+        def host(name: str):
+            return spans.span(name, ctx, phase=phase, t=t)
+
+        with host("host.dispatch"):
+            state, out = self._eng.step(self._eng_state, self._eng_data, t)
         self._eng_state = state
         self.params = state.params
         self.rep = ReputationState(ema=state.rep_ema)
-        delivered = np.asarray(out.delivered)
-        cost, intra_b, cross_b = self._eng.host_round_accounting(
-            delivered[None], t0=t)[0]
-        self.cum_cost += cost
-        self.cum_intra_bytes += intra_b
-        self.cum_cross_bytes += cross_b
-        metrics = RoundMetrics(round=t, cost=cost, cum_cost=self.cum_cost,
-                               selected=delivered,
-                               reputation=np.array(state.rep_ema),
-                               extra={"intra_bytes": intra_b,
-                                      "cross_bytes": cross_b})
-        if self._telemetry_ctx is not None:
-            # same raw inputs and accounting floats as the scan stream
-            # collector → byte-identical round events across drivers
-            self._telemetry_ctx.round(
-                t, delivered, metrics.reputation, float(out.params_l2),
-                cost=float(cost), intra_bytes=float(intra_b),
-                cross_bytes=float(cross_b),
-                feat_weights=(np.asarray(out.feat_weights)
-                              if np.asarray(out.feat_weights).size
-                              else None))
-        self.history.append(metrics)
+        with host("host.fetch"):
+            delivered = np.asarray(out.delivered)
+        with host("host.fetch"):
+            reputation = np.array(state.rep_ema)
+        if ctx is not None:
+            with host("host.fetch"):
+                params_l2 = float(out.params_l2)
+            with host("host.fetch"):
+                feat_weights = np.asarray(out.feat_weights)
+        with host("host.account"):
+            cost, intra_b, cross_b = self._eng.host_round_accounting(
+                delivered[None], t0=t)[0]
+            self.cum_cost += cost
+            self.cum_intra_bytes += intra_b
+            self.cum_cross_bytes += cross_b
+            metrics = RoundMetrics(round=t, cost=cost,
+                                   cum_cost=self.cum_cost,
+                                   selected=delivered, reputation=reputation,
+                                   extra={"intra_bytes": intra_b,
+                                          "cross_bytes": cross_b})
+            if ctx is not None:
+                # same raw inputs and accounting floats as the scan
+                # stream collector → byte-identical round events across
+                # drivers
+                ctx.round(t, delivered, reputation, params_l2,
+                          cost=float(cost), intra_bytes=float(intra_b),
+                          cross_bytes=float(cross_b),
+                          feat_weights=(feat_weights if feat_weights.size
+                                        else None))
+            self.history.append(metrics)
         return metrics
 
     def _run_round_host(self, t: int) -> RoundMetrics:

@@ -368,50 +368,54 @@ def compiled_sharded(shard_static: ShardStatic) -> CompiledShard:
 
         # replicated selection + delivery on the full fleet (identical
         # closures — and therefore identical masks — to the scan engine)
-        sel = _select(state.rep_ema, c_cross_t,
-                      jax.random.fold_in(key, _FOLD_SELECT))
-        delivered = _deliver(sel, jax.random.fold_in(key, _FOLD_DROPOUT))
+        with jax.named_scope("round.select"):
+            sel = _select(state.rep_ema, c_cross_t,
+                          jax.random.fold_in(key, _FOLD_SELECT))
+            delivered = _deliver(sel, jax.random.fold_in(key, _FOLD_DROPOUT))
 
-        i0 = _shard_offset()
-        gids = i0 + jnp.arange(n_loc)
-        valid = jax.lax.dynamic_slice(delivered, (i0,), (n_loc,))
-        rep_loc = jax.lax.dynamic_slice(state.rep_ema, (i0,), (n_loc,))
-        w = valid.astype(jnp.float32)
+            i0 = _shard_offset()
+            gids = i0 + jnp.arange(n_loc)
+            valid = jax.lax.dynamic_slice(delivered, (i0,), (n_loc,))
+            rep_loc = jax.lax.dynamic_slice(state.rep_ema, (i0,), (n_loc,))
+            w = valid.astype(jnp.float32)
 
         # masked local training: every local client trains (fixed
         # shapes), each with the same per-client key as the scan engine
-        keys = jax.random.split(key, n)
-        keys_loc = jax.lax.dynamic_slice(keys, (i0, 0), (n_loc, 2))
-        upd_tree = train_loc(state.params, data.client_x, data.client_y,
-                             keys_loc)
-        flat = ravel_rows(upd_tree)                      # (n_loc, D)
+        with jax.named_scope("round.train"):
+            keys = jax.random.split(key, n)
+            keys_loc = jax.lax.dynamic_slice(keys, (i0, 0), (n_loc, 2))
+            upd_tree = train_loc(state.params, data.client_x, data.client_y,
+                                 keys_loc)
+            flat = ravel_rows(upd_tree)                      # (n_loc, D)
 
         # update attacks on this round's ACTIVE malicious clients
-        mal = data.malicious
-        if st.malice_warmup > 0:
-            mal = mal & (t >= st.malice_warmup)
-        mal_loc = mal & valid
-        flat = _shard_attack(st.attack, flat, mal_loc, (~mal & valid
-                                                        ).astype(jnp.float32),
-                             scale=st.attack_scale, z=st.attack_z)
+        with jax.named_scope("round.attack"):
+            mal = data.malicious
+            if st.malice_warmup > 0:
+                mal = mal & (t >= st.malice_warmup)
+            mal_loc = mal & valid
+            flat = _shard_attack(st.attack, flat, mal_loc,
+                                 (~mal & valid).astype(jnp.float32),
+                                 scale=st.attack_scale, z=st.attack_z)
 
         # client uplink wire (EF residuals live with the shard)
         res_client = state.res_client
         if client_wire_active:
-            ckey = jax.random.fold_in(key, _FOLD_CLIENT_WIRE)
-            if hier:       # every client→edge hop is intra-class
-                flat, res_client = ef_step_masked(lp.intra, flat,
-                                                  res_client, valid, ckey,
-                                                  gids)
-            else:          # flat path: intra or cross by co-location
-                same = jax.lax.dynamic_slice(
-                    (cloud_of_j == agg), (i0,), (n_loc,))
-                flat, res_client = ef_step_masked(
-                    lp.intra, flat, res_client, valid & same,
-                    jax.random.fold_in(ckey, 0), gids)
-                flat, res_client = ef_step_masked(
-                    lp.cross, flat, res_client, valid & ~same,
-                    jax.random.fold_in(ckey, 1), gids)
+            with jax.named_scope("round.compress"):
+                ckey = jax.random.fold_in(key, _FOLD_CLIENT_WIRE)
+                if hier:       # every client→edge hop is intra-class
+                    flat, res_client = ef_step_masked(lp.intra, flat,
+                                                      res_client, valid, ckey,
+                                                      gids)
+                else:          # flat path: intra or cross by co-location
+                    same = jax.lax.dynamic_slice(
+                        (cloud_of_j == agg), (i0,), (n_loc,))
+                    flat, res_client = ef_step_masked(
+                        lp.intra, flat, res_client, valid & same,
+                        jax.random.fold_in(ckey, 0), gids)
+                    flat, res_client = ef_step_masked(
+                        lp.cross, flat, res_client, valid & ~same,
+                        jax.random.fold_in(ckey, 1), gids)
 
         # everything downstream reads the masked wire view: rows that
         # did not deliver (or were never selected) are exact zeros
@@ -422,137 +426,144 @@ def compiled_sharded(shard_static: ShardStatic) -> CompiledShard:
         new_rep = state.rep_ema
         new_feat_sep = state.feat_sep
         feat_w = jnp.zeros((0,), jnp.float32)
-        if hier:
-            f32 = flat.dtype
-            ref_tree = train_ref(state.params, data.ref_x, data.ref_y, key)
-            ref_flat = ravel_rows(ref_tree)
-            ref_ll = ref_flat[:, ll_idx]
-            cloud_loc = gids // n_k                      # (n_loc,)
-            onehot = jax.nn.one_hot(cloud_loc, k, dtype=f32)
-            ref_ll_loc = ref_ll[cloud_loc]
+        with jax.named_scope("round.aggregate"):
+            if hier:
+                f32 = flat.dtype
+                with jax.named_scope("ref_train"):
+                    ref_tree = train_ref(state.params, data.ref_x,
+                                         data.ref_y, key)
+                    ref_flat = ravel_rows(ref_tree)
+                ref_ll = ref_flat[:, ll_idx]
+                cloud_loc = gids // n_k                      # (n_loc,)
+                onehot = jax.nn.one_hot(cloud_loc, k, dtype=f32)
+                ref_ll_loc = ref_ll[cloud_loc]
 
-            # Eq. 7 with the median-damped norm factor: global gbar and
-            # the delivered-norm median from cheap (N,)-sized collectives
-            wsum = _psum(jnp.sum(w))
-            gbar = _psum(w @ ll_loc) / jnp.maximum(wsum, 1.0)
-            norms = jnp.linalg.norm(ll_loc, axis=1)
-            all_norms = jax.lax.all_gather(
-                jnp.where(w > 0, norms, jnp.nan), AXES, tiled=True)
-            med = jnp.nanmedian(all_norms)
-            damp = jnp.minimum(1.0, (med / jnp.maximum(norms, eps)) ** 2)
-            damp = jnp.where(jnp.isnan(damp), 1.0, damp)
-            phi = gradient_contribution(ll_loc, gbar) * damp * w
+                # Eq. 7 with the median-damped norm factor: global gbar and
+                # the delivered-norm median from cheap (N,)-sized collectives
+                wsum = _psum(jnp.sum(w))
+                gbar = _psum(w @ ll_loc) / jnp.maximum(wsum, 1.0)
+                norms = jnp.linalg.norm(ll_loc, axis=1)
+                all_norms = jax.lax.all_gather(
+                    jnp.where(w > 0, norms, jnp.nan), AXES, tiled=True)
+                med = jnp.nanmedian(all_norms)
+                damp = jnp.minimum(1.0, (med / jnp.maximum(norms, eps)) ** 2)
+                damp = jnp.where(jnp.isnan(damp), 1.0, damp)
+                phi = gradient_contribution(ll_loc, gbar) * damp * w
 
-            # multi-feature gate (core.features): features are per-row
-            # (shards own whole rows, gbar/med already globally reduced),
-            # the separability statistics reduce in ONE psum of the
-            # stacked (6, F) sums, and the EMA/weights stay replicated
-            if st.multi_features:
-                feats = feats_mod.client_features(ll_loc, ref_ll_loc,
-                                                  gbar, med, w, eps)
-                sums = _psum(feats_mod.separability_sums(feats, w))
-                sep_round = feats_mod.separability_from_sums(sums, eps)
-                new_feat_sep = (
-                    feats_mod.FEAT_SEP_RHO * state.feat_sep
-                    + (1.0 - feats_mod.FEAT_SEP_RHO) * sep_round)
-                feat_w = feats_mod.feature_weights(new_feat_sep)
-                phi = phi * feats_mod.gate(feats, new_feat_sep)
+                # multi-feature gate (core.features): features are per-row
+                # (shards own whole rows, gbar/med already globally reduced),
+                # the separability statistics reduce in ONE psum of the
+                # stacked (6, F) sums, and the EMA/weights stay replicated
+                if st.multi_features:
+                    feats = feats_mod.client_features(ll_loc, ref_ll_loc,
+                                                      gbar, med, w, eps)
+                    sums = _psum(feats_mod.separability_sums(feats, w))
+                    sep_round = feats_mod.separability_from_sums(sums, eps)
+                    new_feat_sep = (
+                        feats_mod.FEAT_SEP_RHO * state.feat_sep
+                        + (1.0 - feats_mod.FEAT_SEP_RHO) * sep_round)
+                    feat_w = feats_mod.feature_weights(new_feat_sep)
+                    phi = phi * feats_mod.gate(feats, new_feat_sep)
 
-            # Eq. 8–9
-            total = _psum(jnp.sum(phi))
-            r = jnp.where(total > eps, phi / jnp.maximum(total, eps),
-                          1.0 / n)
-            rep_new_loc = (st.ema_gamma * rep_loc
-                           + (1.0 - st.ema_gamma) * r)
-            rep_new_loc = jnp.where(valid, rep_new_loc, rep_loc)
-            new_rep = jax.lax.all_gather(rep_new_loc, AXES, tiled=True)
+                # Eq. 8–9
+                total = _psum(jnp.sum(phi))
+                r = jnp.where(total > eps, phi / jnp.maximum(total, eps),
+                              1.0 / n)
+                rep_new_loc = (st.ema_gamma * rep_loc
+                               + (1.0 - st.ema_gamma) * r)
+                rep_new_loc = jnp.where(valid, rep_new_loc, rep_loc)
+                new_rep = jax.lax.all_gather(rep_new_loc, AXES, tiled=True)
 
-            # Eq. 11: trust vs. the client's own cloud reference
-            dots = jnp.sum(ll_loc * ref_ll_loc, axis=1)
-            cos = dots / jnp.maximum(
-                norms * jnp.linalg.norm(ref_ll_loc, axis=1), eps)
-            ts = jax.nn.relu(cos) * rep_new_loc * w
+                # Eq. 11: trust vs. the client's own cloud reference
+                dots = jnp.sum(ll_loc * ref_ll_loc, axis=1)
+                cos = dots / jnp.maximum(
+                    norms * jnp.linalg.norm(ref_ll_loc, axis=1), eps)
+                ts = jax.nn.relu(cos) * rep_new_loc * w
 
-            # Eq. 12: rescale to own-cloud reference norm
-            ref_norms = jnp.linalg.norm(ref_flat, axis=1)
-            g_tilde = flat * (ref_norms[cloud_loc] / jnp.maximum(
-                jnp.linalg.norm(flat, axis=1), eps))[:, None]
+                # Eq. 12: rescale to own-cloud reference norm
+                ref_norms = jnp.linalg.norm(ref_flat, axis=1)
+                g_tilde = flat * (ref_norms[cloud_loc] / jnp.maximum(
+                    jnp.linalg.norm(flat, axis=1), eps))[:, None]
 
-            # Eq. 5/13: TWO-STAGE reduction. Stage 1 (intra-cloud): each
-            # shard's per-cloud partial sums psum over the client axis —
-            # a cloud's clients all live in one mesh column, so this
-            # completes the cloud aggregates without crossing columns.
-            # Stage 2 (cross-cloud): one combine over the cloud axis
-            # (each cloud's rows are nonzero in exactly one column).
-            ts_cloud = _psum(onehot.T @ ts)                       # (K,)
-            cnt_cloud = _psum(onehot.T @ w)                       # (K,)
-            partial = onehot.T @ (g_tilde * ts[:, None])          # (K, D)
-            cloud_sums = jax.lax.psum(partial, "client")          # stage 1
-            cloud_sums = jax.lax.psum(cloud_sums, "cloud")        # stage 2
-            cloud_aggs = cloud_sums / jnp.maximum(ts_cloud, eps)[:, None]
-            if edge_wire_active:
-                # edge→global wire on the (now replicated) aggregates —
-                # the SAME shared EF closure as the scan engine, only
-                # `active` is derived from the psum'd per-cloud counts
-                active = (cnt_cloud > 0)[:, None]
-                cloud_aggs, res_edge = _edge_wire(
-                    cloud_aggs, res_edge, active,
-                    jax.random.fold_in(key, _FOLD_EDGE_WIRE))
-            # empty/zero-trust clouds fall back to their reference update
-            cloud_aggs = jnp.where((ts_cloud > eps)[:, None], cloud_aggs,
-                                   ref_flat)
+                # Eq. 5/13: TWO-STAGE reduction. Stage 1 (intra-cloud): each
+                # shard's per-cloud partial sums psum over the client axis —
+                # a cloud's clients all live in one mesh column, so this
+                # completes the cloud aggregates without crossing columns.
+                # Stage 2 (cross-cloud): one combine over the cloud axis
+                # (each cloud's rows are nonzero in exactly one column).
+                ts_cloud = _psum(onehot.T @ ts)                       # (K,)
+                cnt_cloud = _psum(onehot.T @ w)                       # (K,)
+                partial = onehot.T @ (g_tilde * ts[:, None])          # (K, D)
+                cloud_sums = jax.lax.psum(partial, "client")          # stage 1
+                cloud_sums = jax.lax.psum(cloud_sums, "cloud")        # stage 2
+                cloud_aggs = cloud_sums / jnp.maximum(ts_cloud, eps)[:, None]
+                if edge_wire_active:
+                    # edge→global wire on the (now replicated) aggregates —
+                    # the SAME shared EF closure as the scan engine, only
+                    # `active` is derived from the psum'd per-cloud counts
+                    active = (cnt_cloud > 0)[:, None]
+                    with jax.named_scope("edge_codec"):
+                        cloud_aggs, res_edge = _edge_wire(
+                            cloud_aggs, res_edge, active,
+                            jax.random.fold_in(key, _FOLD_EDGE_WIRE))
+                # empty/zero-trust clouds fall back to their reference update
+                cloud_aggs = jnp.where((ts_cloud > eps)[:, None], cloud_aggs,
+                                       ref_flat)
 
-            # Eq. 6: cross-cloud phase, β_k from the global reference
-            beta = cloud_trust(cloud_aggs, jnp.mean(ref_flat, axis=0))
-            update = beta @ cloud_aggs
-        else:
-            if st.method == "fedavg":
-                update = _psum(w @ flat) / jnp.maximum(_psum(jnp.sum(w)),
-                                                       1.0)
-            elif st.method == "fltrust":
-                ref_tree = train_ref(state.params, data.ref_x, data.ref_y,
-                                     key)
-                ref = jnp.mean(ravel_rows(ref_tree), axis=0)
-                refn = jnp.linalg.norm(ref)
-                norms = jnp.linalg.norm(flat, axis=1)
-                cos = (flat @ ref) / jnp.maximum(norms * refn, eps)
-                ts = jax.nn.relu(cos) * w
-                g_tilde = flat * (refn / jnp.maximum(norms, eps))[:, None]
-                update = (_psum(ts @ g_tilde)
-                          / jnp.maximum(_psum(jnp.sum(ts)), eps))
+                # Eq. 6: cross-cloud phase, β_k from the global reference
+                beta = cloud_trust(cloud_aggs, jnp.mean(ref_flat, axis=0))
+                update = beta @ cloud_aggs
             else:
-                # order statistics need the selected matrix as ONE array:
-                # re-materialize it replicated via a slot-scatter psum —
-                # rows land at their cumsum(sel) position, i.e. the exact
-                # sel_idx order of the scan engine
-                sel_loc = jax.lax.dynamic_slice(sel, (i0,), (n_loc,))
-                slot = jnp.cumsum(sel) - 1                       # (N,)
-                slot_loc = jnp.clip(
-                    jax.lax.dynamic_slice(slot, (i0,), (n_loc,)), 0,
-                    m_total - 1)
-                buf = jnp.zeros((m_total, flat.shape[1]), flat.dtype)
-                buf = buf.at[slot_loc].add(
-                    jnp.where(sel_loc[:, None], flat, 0.0))
-                u = _psum(buf)                                   # (m, D)
-                if st.method == "krum":
-                    update = krum(u, f_mal,
-                                  multi=max(1, m_total - f_mal - 2))
-                elif st.method == "trimmed_mean":
-                    update = trimmed_mean(u,
-                                          trim_frac=st.malicious_frac / 2)
+                if st.method == "fedavg":
+                    update = _psum(w @ flat) / jnp.maximum(_psum(jnp.sum(w)),
+                                                           1.0)
+                elif st.method == "fltrust":
+                    with jax.named_scope("ref_train"):
+                        ref_tree = train_ref(state.params, data.ref_x,
+                                             data.ref_y, key)
+                        ref_flat = ravel_rows(ref_tree)
+                    ref = jnp.mean(ref_flat, axis=0)
+                    refn = jnp.linalg.norm(ref)
+                    norms = jnp.linalg.norm(flat, axis=1)
+                    cos = (flat @ ref) / jnp.maximum(norms * refn, eps)
+                    ts = jax.nn.relu(cos) * w
+                    g_tilde = flat * (refn / jnp.maximum(norms, eps))[:, None]
+                    update = (_psum(ts @ g_tilde)
+                              / jnp.maximum(_psum(jnp.sum(ts)), eps))
                 else:
-                    update = coordinate_median(u)
+                    # order statistics need the selected matrix as ONE array:
+                    # re-materialize it replicated via a slot-scatter psum —
+                    # rows land at their cumsum(sel) position, i.e. the exact
+                    # sel_idx order of the scan engine
+                    sel_loc = jax.lax.dynamic_slice(sel, (i0,), (n_loc,))
+                    slot = jnp.cumsum(sel) - 1                       # (N,)
+                    slot_loc = jnp.clip(
+                        jax.lax.dynamic_slice(slot, (i0,), (n_loc,)), 0,
+                        m_total - 1)
+                    buf = jnp.zeros((m_total, flat.shape[1]), flat.dtype)
+                    buf = buf.at[slot_loc].add(
+                        jnp.where(sel_loc[:, None], flat, 0.0))
+                    u = _psum(buf)                                   # (m, D)
+                    if st.method == "krum":
+                        update = krum(u, f_mal,
+                                      multi=max(1, m_total - f_mal - 2))
+                    elif st.method == "trimmed_mean":
+                        update = trimmed_mean(u,
+                                              trim_frac=st.malicious_frac / 2)
+                    else:
+                        update = coordinate_median(u)
 
-        # apply: w <- w - eta * g  (replicated)
-        delta = unflatten_like(update * st.server_lr, state.params)
-        params = jax.tree.map(lambda p, g: p - g, state.params, delta)
+            # apply: w <- w - eta * g  (replicated)
+            delta = unflatten_like(update * st.server_lr, state.params)
+            params = jax.tree.map(lambda p, g: p - g, state.params, delta)
 
         # byte-exact wire accounting from the replicated delivered mask —
         # the same reduction as the scan engine, bit-identical masks in,
         # bit-identical bytes out
-        intra_b, cross_b = round_bytes_jax(delivered, cloud_of_j, agg,
-                                           cp_j, ep_j, hierarchical=hier)
-        cost = (intra_b * st.c_intra + cross_b * c_cross_t) / _GB
+        with jax.named_scope("round.account"):
+            intra_b, cross_b = round_bytes_jax(delivered, cloud_of_j, agg,
+                                               cp_j, ep_j, hierarchical=hier)
+            cost = (intra_b * st.c_intra + cross_b * c_cross_t) / _GB
 
         new_state = RoundState(
             params=params, rep_ema=new_rep, res_client=res_client,

@@ -19,7 +19,6 @@ from repro.federated import client as client_mod
 from repro.federated import engine as engine_mod
 from repro.federated.server import FLServer
 from repro.scenarios import Scenario, get_scenario
-from repro.telemetry import spans
 from repro.telemetry import taps as taps_mod
 from repro.telemetry.schema import RunContext
 from repro.telemetry.taps import TapSpec

@@ -15,9 +15,9 @@ then ``python -m repro.telemetry.report events.jsonl``.
 Layout: ``schema`` (event types + the ``RunContext`` factory +
 validation), ``sinks`` (JSONL / ring buffer / recorder), ``taps``
 (ordered ``jax.debug.callback`` streaming out of jitted scans — zero
-ops when disabled), ``spans`` (TraceAnnotation timing + Perfetto
-capture), ``provenance`` (git/host stamps), ``report`` (validation CLI
-+ wire-breakdown tables from events alone).
+ops when disabled), ``spans`` (TraceAnnotation host spans with timed
+``span`` events), ``provenance`` (git/host stamps), ``report``
+(validation CLI + wire-breakdown tables from events alone).
 """
 from repro.telemetry.provenance import stamp
 from repro.telemetry.schema import (ENGINES, EVENT_TYPES, SCHEMA,
@@ -25,7 +25,7 @@ from repro.telemetry.schema import (ENGINES, EVENT_TYPES, SCHEMA,
                                     validate_event, validate_events)
 from repro.telemetry.sinks import (JsonlSink, ListSink, RingBufferSink,
                                    Telemetry)
-from repro.telemetry.spans import span, start_trace, stop_trace, trace
+from repro.telemetry.spans import span
 from repro.telemetry.taps import TapSpec, collecting, instrument
 
 __all__ = [
@@ -33,5 +33,5 @@ __all__ = [
     "encode", "validate_event", "validate_events",
     "Telemetry", "JsonlSink", "RingBufferSink", "ListSink",
     "TapSpec", "collecting", "instrument",
-    "span", "trace", "start_trace", "stop_trace", "stamp",
+    "span", "stamp",
 ]

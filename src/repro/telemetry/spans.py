@@ -1,19 +1,19 @@
-"""Host-side timing spans and profiler hooks.
+"""Host-side timing spans.
 
-Two annotation layers:
+Two annotation layers label a round for the profiler:
 
 * inside jitted code, ``jax.named_scope`` labels the round phases
-  (``engine.round_step`` wraps select/train/attack/compress/aggregate/
-  account) — the names show up in jaxprs, HLO metadata and profiler
-  traces, and cost nothing at runtime;
+  (``round.select/train/attack/compress/aggregate/account``, with
+  ``ref_train`` and ``edge_codec`` nested in ``round.aggregate``) on
+  both device engines; the names land in HLO ``op_name`` metadata and
+  profiler traces, and cost nothing at runtime;
 * on the host, :func:`span` wraps a block in
-  ``jax.profiler.TraceAnnotation`` (visible in Perfetto) AND times it
-  with ``perf_counter``, optionally emitting a ``span`` event — this is
-  how drivers separate compile (first call) from steady-state execute.
+  ``jax.profiler.TraceAnnotation``. ``FLServer.run_round`` opens a
+  ``round`` span on every call, and on the device engines its
+  ``host.dispatch`` / ``host.fetch`` / ``host.account`` children.
 
-:func:`trace` is the opt-in Perfetto capture: wrap any driver call and
-point ``jax.profiler``'s trace at a directory, then load the dump at
-``ui.perfetto.dev``.
+Capture them with ``jax.profiler.trace(logdir)`` around any driver call
+and load the dump in Perfetto (``ui.perfetto.dev``) or TensorBoard.
 """
 from __future__ import annotations
 
@@ -24,49 +24,20 @@ from typing import Any, Iterator, Optional
 import jax
 
 
-class SpanTimer:
-    """Mutable result handle yielded by :func:`span` (``seconds`` is
-    populated when the block exits)."""
-
-    def __init__(self, name: str):
-        self.name = name
-        self.seconds: float = 0.0
-
-
 @contextmanager
 def span(name: str, context: Optional[Any] = None, *,
          phase: Optional[str] = None,
-         t: Optional[int] = None) -> Iterator[SpanTimer]:
-    """Time a host-side block under a profiler ``TraceAnnotation``.
+         t: Optional[int] = None) -> Iterator[None]:
+    """Run a host-side block under a profiler ``TraceAnnotation``.
 
     ``context`` — an optional ``schema.RunContext``: when given, a
-    ``span`` event is emitted on exit (even if the block raised, so a
-    crashing round still records how far it got)."""
-    timer = SpanTimer(name)
+    ``span`` event with the block's seconds is emitted on exit (even if
+    the block raised, so a crashing round still records how far it
+    got)."""
     t0 = time.perf_counter()
     try:
         with jax.profiler.TraceAnnotation(name):
-            yield timer
+            yield
     finally:
-        timer.seconds = time.perf_counter() - t0
         if context is not None:
-            context.span(name, timer.seconds, phase=phase, t=t)
-
-
-def start_trace(logdir: str) -> None:
-    """Start a Perfetto-compatible profiler capture into ``logdir``."""
-    jax.profiler.start_trace(logdir)
-
-
-def stop_trace() -> None:
-    jax.profiler.stop_trace()
-
-
-@contextmanager
-def trace(logdir: str) -> Iterator[None]:
-    """Capture a Perfetto trace of the ``with`` body into ``logdir``."""
-    start_trace(logdir)
-    try:
-        yield
-    finally:
-        stop_trace()
+            context.span(name, time.perf_counter() - t0, phase=phase, t=t)
