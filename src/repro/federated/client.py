@@ -35,22 +35,35 @@ def cnn_init(key: Array, input_shape: Tuple[int, int, int],
     }
 
 
+def _conv_im2col(x: Array, w: Array) -> Array:
+    """3x3 SAME convolution as one GEMM over the nine shifted patches."""
+    _, h, ww, c = x.shape
+    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
+    patches = jnp.concatenate(
+        [xp[:, i:i + h, j:j + ww, :] for i in range(3) for j in range(3)],
+        axis=-1)                                          # (B,H,W,9C)
+    return patches @ w.reshape(9 * c, -1)
+
+
+def _conv_native(x: Array, w: Array) -> Array:
+    """3x3 SAME convolution as XLA's own convolution op."""
+    return jax.lax.conv_general_dilated(
+        x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+
+def _conv(x: Array, w: Array) -> Array:
+    # im2col lane-pads its patches on TPU; XLA:CPU's direct conv is 16x slower
+    return jax.lax.platform_dependent(x, w, cpu=_conv_im2col,
+                                      tpu=_conv_native)
+
+
 def cnn_apply(params: Params, x: Array) -> Array:
     """x: (B, H, W, C) -> logits (B, n_classes)."""
     def conv(x, w, b):
-        # im2col + GEMM: identical math to a SAME 3x3 conv, but lowers to
-        # a fast matmul (XLA-CPU's direct conv path is ~50x slower)
-        bsz, h, ww, c = x.shape
-        xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
-        patches = jnp.concatenate(
-            [xp[:, i:i + h, j:j + ww, :] for i in range(3)
-             for j in range(3)], axis=-1)                 # (B,H,W,9C)
-        y = patches @ w.reshape(9 * c, -1)
-        return jax.nn.relu(y + b)
+        return jax.nn.relu(_conv(x, w) + b)
 
     def pool(x):
-        # reshape-based 2x2 max-pool (XLA-CPU reduce_window is ~100x
-        # slower; this lowers to fast vectorized code on CPU and TPU)
+        # the reference's reshape pool; reduce_window's grad: 6x slower (CPU)
         b, h, w, c = x.shape
         return x.reshape(b, h // 2, 2, w // 2, 2, c).max(axis=(2, 4))
 

@@ -8,11 +8,14 @@ on the CPU hides both. The widths are the paper's: D = 545,098 (the
 CIFAR-10-shaped CNN) for the codec and aggregation kernels, the 1,290
 last-layer values for the trust kernels, with m = 30 selected rows (the
 paper's round) and m = 6; ``linear_scan`` at RecurrentGemma's 2,560.
+The scan engine's whole round step is compiled too, at a small fleet, to
+see which form of the CNN's convolution the train loop holds.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library at a time.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +23,8 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import FLConfig
+from repro.federated import FLServer, make_data, make_topology
 from repro.kernels import ops, ref
 
 D_MODEL = 545_098        # CNN on 32x32x3, from client.cnn_init
@@ -44,6 +49,16 @@ def one_chip():
         yield SingleDeviceSharding(topo.devices[0])
     finally:
         jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def described_chip(request):
+    """``one_chip``, or a skip where no v5e topology can be described (the
+    TPU compiler is missing, or another process holds it)."""
+    try:
+        return request.getfixturevalue("one_chip")
+    except RuntimeError as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
 def _compiled_hlo(fn, *shapes, sharding):
@@ -107,3 +122,49 @@ def test_ops_interpret_on_cpu_and_refuse_unknown_platforms():
                                   np.asarray(ref.topk_mask_ref(g, thr)))
     with pytest.raises(NotImplementedError, match="platform_index"):
         fn.trace(g).lower(lowering_platforms=("cuda",))
+
+
+_TRAIN_OP = re.compile(r"= \w+\[([\d,]*)\]\S* (\w[\w-]*)\((.*)"
+                       r'op_name="jit\(round_step\)/round\.train/')
+
+
+def _train_ops(hlo):
+    """(opcode, shape, operands and attributes) of every op under the
+    round's ``round.train`` scope."""
+    return [(m.group(2), tuple(int(d) for d in m.group(1).split(",") if d),
+             m.group(3))
+            for m in map(_TRAIN_OP.search, hlo.splitlines()) if m]
+
+
+def test_scan_step_convolves_natively_on_tpu_and_by_im2col_on_cpu(
+        described_chip):
+    """Lowered for the TPU, the clients' train loop convolves the 32x32x3
+    images with 3x3 windows and builds no 27- or 288-wide im2col patches;
+    lowered for the CPU it builds those patches and has no such window."""
+    fl = FLConfig(n_clouds=2, clients_per_cloud=2, clients_per_round=4,
+                  local_epochs=1, local_batch=4, ref_samples=8)
+    data = make_data(fl, "cifar10", seed=0, n_samples=200,
+                     samples_per_client=8)
+    srv = FLServer(fl, make_topology(fl), data, method="cost_trustfl",
+                   seed=0, engine="jit")
+    shape = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=described_chip)
+    on_tpu = srv._eng.step.lower(
+        jax.tree.map(shape, srv._eng_state),
+        jax.tree.map(shape, srv._eng_data),
+        jax.ShapeDtypeStruct((), jnp.int32, weak_type=True,
+                             sharding=described_chip)).compile().as_text()
+    on_cpu = srv._eng.step.lower(srv._eng_state, srv._eng_data,
+                                 0).compile().as_text()
+    patches = {9 * 3, 9 * 32}          # 3x3 taps of the image, of conv1
+    for hlo, native in ((on_tpu, True), (on_cpu, False)):
+        ops_ = _train_ops(hlo)
+        windows = [s for op, s, rest in ops_
+                   if op == "convolution" and "window={size=3x3" in rest]
+        widths = {s[-1] for op, s, _ in ops_ if op == "concatenate" and s}
+        if native:       # conv1 over the image: 32x32 out, 32 channels
+            assert (32, 32, 32) in {s[-3:] for s in windows}, windows
+            assert not widths & patches, widths
+        else:
+            assert ops_ and not windows, windows
+            assert patches <= widths, widths
